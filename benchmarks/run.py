@@ -14,9 +14,13 @@ from __future__ import annotations
 
 import json
 import os
+from pathlib import Path
 
 
 def main() -> None:
+    import repro
+    repro.enable_compile_cache(Path(__file__).resolve().parents[1]
+                               / ".jax_cache")
     from benchmarks import (bench_als, bench_estimators, bench_io,
                             bench_kmeans, bench_lazy, bench_matmul,
                             bench_serve, bench_shuffle, bench_slicing,

@@ -29,6 +29,15 @@ from repro.estimators.base import BaseEstimator, _FitCheckpoint, \
     _fire, _iter_span
 
 
+# The distance and centre-sum contractions run at full f32 precision: XLA's
+# default on a TPU takes f32 operands in one bf16 pass, which sends about one
+# row in a thousand of a high-dimensional dataset to a centre that is not its
+# nearest.  Each contraction here reads the whole array against only k
+# centres, a few flops per byte, so the extra MXU passes should stay hidden
+# behind the HBM reads.
+_F32 = jax.lax.Precision.HIGHEST
+
+
 def _row_sq_norms(x: DsArray) -> jnp.ndarray:
     """Per-row squared norms ``(gn, bn)`` via ONE fused lazy plan.
 
@@ -55,7 +64,7 @@ def _dots(blocks, c_blocks: jnp.ndarray) -> jnp.ndarray:
     if isinstance(blocks, BCOO):
         return jsparse.bcoo_dot_general(
             blocks, c_blocks, dimension_numbers=(((1, 3), (1, 2)), ((), ())))
-    return jnp.einsum("ijab,kjb->iak", blocks, c_blocks,
+    return jnp.einsum("ijab,kjb->iak", blocks, c_blocks, precision=_F32,
                       preferred_element_type=jnp.float32)
 
 
@@ -76,23 +85,20 @@ def _center_stats(blocks, row_valid: jnp.ndarray,
     k = centers.shape[0]
     c_blocks = centers.reshape(k, gm, bm)
     dots = _dots(blocks, c_blocks)                          # (gn, bn, k)
-    c_sq = jnp.einsum("km,km->k", centers, centers,
+    c_sq = jnp.einsum("km,km->k", centers, centers, precision=_F32,
                       preferred_element_type=jnp.float32)
     dist = x_sq[..., None] - 2.0 * dots + c_sq[None, None, :]
     labels = jnp.argmin(dist, axis=-1)                      # (gn, bn)
     onehot = jax.nn.one_hot(labels, k, dtype=blocks.dtype)  # (gn, bn, k)
     onehot = onehot * row_valid[..., None].astype(blocks.dtype)
     if isinstance(blocks, BCOO):
-        # onehotᵀ · x with the SPARSE side on the left (the dense-lhs form
-        # hits a jax-0.4.37 bcoo batching bug): contract the (gn, bn)
-        # sample dims -> (gm, bm, k), then relabel to (k, gm*bm)
+        # onehotᵀ · x: contract the (gn, bn) sample dims -> (k, gm, bm)
         sums = jsparse.bcoo_dot_general(
-            blocks, onehot, dimension_numbers=(((0, 2), (0, 1)), ((), ())))
-        sums = sums.transpose(2, 0, 1).reshape(k, gm * bm)
+            onehot, blocks, dimension_numbers=(((0, 1), (0, 2)), ((), ())))
     else:
-        sums = jnp.einsum("iak,ijab->kjb", onehot, blocks,
+        sums = jnp.einsum("iak,ijab->kjb", onehot, blocks, precision=_F32,
                           preferred_element_type=jnp.float32)
-        sums = sums.reshape(k, gm * bm)
+    sums = sums.reshape(k, gm * bm)
     counts = onehot.sum(axis=(0, 1))
     return labels, sums, counts
 
@@ -165,7 +171,7 @@ def _d2_to_center(blocks, row_valid: jnp.ndarray,
         d2 = jnp.maximum(x_sq - 2.0 * dots + c_sq, 0.0)
     else:
         diff = blocks - c_blocks[None, :, None, :]
-        d2 = jnp.einsum("ijab,ijab->ia", diff, diff,
+        d2 = jnp.einsum("ijab,ijab->ia", diff, diff, precision=_F32,
                         preferred_element_type=jnp.float32)
     return d2 * row_valid.astype(d2.dtype)
 
@@ -321,7 +327,7 @@ class KMeans(BaseEstimator):
         c_blocks = centers.reshape(-1, gm, bm)
         dots = _dots(x.blocks, c_blocks)
         x_sq = _row_sq_norms(x)
-        c_sq = jnp.einsum("km,km->k", centers, centers)
+        c_sq = jnp.einsum("km,km->k", centers, centers, precision=_F32)
         dist = x_sq[..., None] - 2 * dots + c_sq[None, None, :]
         best = dist.min(axis=-1)
         best = best * self._row_valid(x)

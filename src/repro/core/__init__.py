@@ -23,7 +23,7 @@ from repro.core.dsarray import (
     zeros,
 )
 from repro.core.shuffle import exact_shuffle, pseudo_shuffle
-from repro.core import compat, costmodel, structural
+from repro.core import costmodel, structural
 from repro.core import sparse
 from repro.core.sparse import from_scipy, random_sparse
 from repro.core import expr, plan
@@ -37,7 +37,7 @@ __all__ = [
     "PadState", "PAD_ZERO", "PAD_DIRTY", "pad_state_of",
     "from_array", "zeros", "full", "eye", "identity_like", "random_array",
     "concat_rows", "pseudo_shuffle", "exact_shuffle", "costmodel",
-    "compat", "structural", "gram", "take_rows", "take_cols",
+    "structural", "gram", "take_rows", "take_cols",
     "apply_along_axis", "matmul_ta",
     "sparse", "from_scipy", "random_sparse",
     "expr", "plan", "LazyDsArray", "lazy", "compute", "compute_multi",

@@ -829,12 +829,15 @@ class DsArray:
         The local contraction over (grid-k, block-k) — exactly the paper's
         per-block task graph — lowers through the fused Pallas MXU kernel
         (``kernels.matmul.stacked_matmul``: one launch, fp32 VMEM accumulator,
-        one HBM write per C tile) on TPU, with a stacked-block einsum fallback
-        off-TPU / for non-MXU shapes; under pjit the grid contraction becomes
-        a psum/SUMMA schedule chosen by SPMD partitioning (see
-        core/shmap_ops.py for the explicitly-scheduled version used in §Perf).
-        Zero pad on both operands makes the padded contraction exact; the
-        result pad is therefore exactly zero.
+        one HBM write per C tile) on a TPU for aligned f32/bf16 blocks on one
+        device, and through a stacked-block einsum otherwise.  Operands
+        placed on a mesh by ``distribute`` always take the einsum, whose grid
+        contraction SPMD partitioning schedules: a Mosaic kernel cannot be
+        partitioned automatically.  ``core/shmap_ops.py`` holds the
+        explicitly scheduled SUMMA and Cannon, which run the kernel on each
+        device's local blocks inside ``shard_map``.  Zero pad on both
+        operands makes the padded contraction exact; the result pad is
+        therefore exactly zero.
         """
         from repro.kernels.matmul.ops import local_matmul
         if _lazy_mode():

@@ -534,7 +534,7 @@ class Plan:
         with _expr.suspend_lazy():
             return jax.jit(self._make_run()).lower(*self.leaf_values())
 
-    def compile_aot(self, donate_argnums: tuple = ()) -> bool:
+    def compile_aot(self) -> bool:
         """Ahead-of-time compile this plan into the shared compiled-plan
         cache: ``jit(body).lower().compile()`` on the current leaf values'
         avals, keyed by the same structural :attr:`key` ``execute`` looks
@@ -548,27 +548,14 @@ class Plan:
         ``execute()`` that maps to this key binds leaf values of identical
         geometry/dtype/format, so the warmed executable replays on every
         later request batch.
-
-        ``donate_argnums`` (positions into :attr:`leaves`) marks leaf
-        buffers the executable may alias for its outputs — the serving
-        layer donates the packed request batch (a per-request temporary),
-        which removes one batch-sized HBM copy per predict on accelerators.
-        Caveat: the executable lives in the SHARED structural cache, so
-        every ``execute()`` mapping to this key consumes the donation —
-        donate only leaves that are always per-call temporaries (never
-        fitted model state), as the caller's donated buffer is invalidated
-        on backends that implement donation (CPU ignores it).
         """
         cached = _CACHE.get(self.key)
         if cached is not None:
             _CACHE.move_to_end(self.key)
             return False
-        with _tracing.span("plan.aot_compile", inputs=len(self.leaves),
-                           donated=len(tuple(donate_argnums))):
+        with _tracing.span("plan.aot_compile", inputs=len(self.leaves)):
             with _expr.suspend_lazy():
-                compiled = jax.jit(
-                    self._make_run(),
-                    donate_argnums=tuple(donate_argnums)).lower(
+                compiled = jax.jit(self._make_run()).lower(
                     *self.leaf_values()).compile()
         _STATS.inc("aot_compiles")
         _CACHE[self.key] = compiled

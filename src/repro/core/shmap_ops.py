@@ -30,13 +30,12 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.blocking import BlockGrid, round_up
-from repro.core.compat import shard_map
 from repro.core.dsarray import DsArray
 from repro.core import structural
 
 
 def _shmap(body, mesh, in_specs, out_specs):
-    return shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
                      check_vma=False)
 
 

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
-
 
 def _kmeans_kernel(x_ref, c_ref, labels_ref, sums_ref, counts_ref, *,
                    n: int, block_n: int):
@@ -84,7 +82,7 @@ def kmeans_assign_padded(
             jax.ShapeDtypeStruct((k_pad, d), jnp.float32),
             jax.ShapeDtypeStruct((k_pad, 128), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
-
 
 def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
     k = pl.program_id(2)
@@ -62,9 +60,19 @@ def _stacked_matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int,
         o_ref[0, 0] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _pick_tile(dim: int, target: int) -> int:
-    """Sub-tile a block dim only when it divides evenly; else take it whole."""
-    return target if (dim > target and dim % target == 0) else dim
+def _pick_tile(dim: int, target: int, unit: int) -> int:
+    """Tile for one block dim: the whole dim when it is at most ``target``,
+    else the largest divisor of ``dim`` that is a multiple of ``unit`` and at
+    most ``target``.  ``unit`` is 8 for a dim on the sublanes of its tiles and
+    128 for one on the lanes: Mosaic accepts a tile dim that is a multiple of
+    those or the whole array dim.  Only a dim with no such divisor is taken
+    whole; a whole 10,000-row block would overflow the scoped VMEM."""
+    if dim <= target:
+        return dim
+    for t in range(target - target % unit, 0, -unit):
+        if dim % t == 0:
+            return t
+    return dim
 
 
 @functools.partial(
@@ -100,9 +108,10 @@ def stacked_matmul(
     tile transpose fed to the MXU — the relayout of the full stacked tensor
     that an eager ``A.T`` would materialize in HBM never happens.
 
-    Block dims larger than ``block_*`` are sub-tiled when they divide evenly;
-    otherwise the whole block is one tile (ds-array blocks are VMEM-sized by
-    construction).  ``interpret=True`` runs the same kernel off-TPU.
+    Block dims larger than ``block_*`` are sub-tiled by the largest aligned
+    divisor at most the target (``_pick_tile``), so a tile never outgrows
+    the scoped VMEM whatever the block height.  ``interpret=True`` runs the
+    same kernel off-TPU.
     """
     if transpose_a:
         gk, gi, bk, bn = a.shape
@@ -113,8 +122,10 @@ def stacked_matmul(
     if gk != gk2 or bk != bk2:
         raise ValueError(f"stacked matmul inner mismatch {a.shape} x {b.shape}")
     out_dtype = out_dtype or jnp.promote_types(a.dtype, b.dtype)
-    tm, tn, tk = (_pick_tile(bn, block_m), _pick_tile(bm, block_n),
-                  _pick_tile(bk, block_k))
+    # A's tile is (tm, tk), or (tk, tm) under transpose_a; B's is (tk, tn)
+    tm = _pick_tile(bn, block_m, 128 if transpose_a else 8)
+    tn = _pick_tile(bm, block_n, 128)
+    tk = _pick_tile(bk, block_k, 8 if transpose_a else 128)
     fm, fn, fk = bn // tm, bm // tn, bk // tk
     grid = (gi * fm, gj * fn, gk * fk)
     if transpose_a:
@@ -138,7 +149,7 @@ def stacked_matmul(
                                lambda i, j, k: (i // fm, j // fn, i % fm, j % fn)),
         out_shape=jax.ShapeDtypeStruct((gi, gj, bn, bm), out_dtype),
         scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -173,7 +184,7 @@ def matmul_padded(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

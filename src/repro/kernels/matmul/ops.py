@@ -4,14 +4,19 @@
 to MXU-aligned block multiples (pad contributes zeros to the K reduction, so
 results are exact) and the output is sliced back.
 
-``local_matmul(a, b)`` is the local GEMM under every distributed ds-array
-``@`` and every shmap schedule: it takes the stacked block tensors directly
-and dispatches to the fused Pallas ``stacked_matmul`` kernel on TPU (or in
-interpret mode), falling back to a stacked-block ``jnp.einsum`` off-TPU or
-for shapes/dtypes the MXU path does not cover.  The backend can be forced
-with the ``REPRO_GEMM`` env var (``pallas`` / ``interpret`` / ``einsum``) or
-the ``backend=`` argument — tests use ``interpret`` to assert the Pallas
-lowering without TPU hardware.
+``local_matmul(a, b)`` is the local GEMM under every ds-array ``@``, every
+shmap schedule and the lazy plan's folded ``Aᵀ @ B``: it takes the stacked
+block tensors directly.  ``gemm_backend`` sends it to the compiled Pallas
+``stacked_matmul`` kernel on a TPU for f32/bf16 blocks whose dims meet the
+Mosaic tiling (lanes a multiple of 128, sublanes of 8), and to a
+stacked-block ``jnp.einsum`` everywhere else: off the TPU, for other dtypes
+(Mosaic refuses f16 loads), for unaligned blocks, and for an operand whose
+blocks lie on a mesh that XLA partitions (a Mosaic kernel cannot be
+partitioned automatically; inside ``shard_map`` the operands are local, so
+SUMMA and Cannon keep the kernel).  The backend can be forced with the
+``REPRO_GEMM`` env var (``pallas`` / ``interpret`` / ``einsum``) or the
+``backend=`` argument; tests use ``interpret`` to run the Pallas kernel in
+the interpreter on the CPU.
 
 A BCOO-blocked A (``core.sparse``) takes the sparse dispatch table instead:
 one ``bcoo_dot_general`` over (grid-k, block-k), never densifying A.
@@ -28,6 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import sparse as jsparse
 from jax.experimental.sparse import BCOO
+from jax.sharding import AxisType
 
 from repro.core.blocking import round_up
 from repro.kernels.matmul.kernel import matmul_padded, stacked_matmul
@@ -53,7 +59,8 @@ def _fire(site: str, **info) -> None:
         ri.maybe_fire(site, **info)
 
 
-_PALLAS_DTYPES = (jnp.float32, jnp.bfloat16, jnp.float16)
+# Mosaic refuses the f16 kernel ("Invalid vector type for load")
+_PALLAS_DTYPES = (jnp.float32, jnp.bfloat16)
 
 # ---------------------------------------------------------------------------
 # Sparse local GEMM: einsum-style dispatch table for BCOO-blocked operands.
@@ -85,21 +92,35 @@ def _sparse_local_matmul(a: BCOO, b: jnp.ndarray, *, out_dtype,
     return out.transpose(out_perm).astype(out_dtype)
 
 
-def _mxu_aligned(bn: int, bk: int, bm: int) -> bool:
-    """True when the block dims keep the MXU/VPU tiling constraints without
-    implicit padding: sublane multiples of 8, lane multiples of 128."""
+def _mxu_aligned(bn: int, bk: int, bm: int, transpose_a: bool = False) -> bool:
+    """True when every tile dim meets the Mosaic tiling without implicit
+    padding: lane dims a multiple of 128, sublane dims of 8.  A's tile is
+    ``(bn, bk)``, or ``(bk, bn)`` under ``transpose_a``; B's is ``(bk, bm)``."""
+    if transpose_a:
+        return bn % 128 == 0 and bk % 8 == 0 and bm % 128 == 0
     return bn % 8 == 0 and bk % 128 == 0 and bm % 128 == 0
 
 
+def _auto_partitioned(x) -> bool:
+    """True when ``x`` lives on a mesh whose axes XLA partitions by itself.
+    The mesh is part of the value's type, so this holds for concrete and
+    traced values alike.  Inside ``shard_map`` the axes are Manual: each
+    device holds its own blocks, and the kernel runs on them."""
+    mesh = jax.typeof(x).sharding.mesh
+    return any(size > 1 and kind != AxisType.Manual
+               for size, kind in zip(mesh.axis_sizes, mesh.axis_types))
+
+
 def gemm_backend(bn: int, bk: int, bm: int, dtype,
-                 backend: Optional[str] = None) -> str:
+                 backend: Optional[str] = None,
+                 transpose_a: bool = False) -> str:
     """Resolve the local-GEMM backend: "pallas" | "interpret" | "einsum".
 
     Priority: explicit ``backend`` arg > ``REPRO_GEMM`` env var > auto.  Auto
-    picks the compiled Pallas kernel exactly when it can win: TPU backend,
-    float dtype the fp32-accumulator path covers, MXU-aligned block dims.
-    Everything else (CPU/GPU, ints, ragged blocks) takes the einsum path,
-    which XLA fuses fine at small scale.
+    picks the compiled Pallas kernel exactly when Mosaic takes it: TPU
+    backend, a dtype in ``_PALLAS_DTYPES``, MXU-aligned block dims.
+    Everything else (CPU/GPU, f16, ints, ragged blocks) takes the einsum
+    path.
     """
     forced = (backend or os.environ.get("REPRO_GEMM", "auto")).lower()
     if forced in ("pallas", "interpret", "einsum"):
@@ -111,7 +132,7 @@ def gemm_backend(bn: int, bk: int, bm: int, dtype,
         return "einsum"
     if dtype not in [jnp.dtype(d) for d in _PALLAS_DTYPES]:
         return "einsum"
-    if not _mxu_aligned(bn, bk, bm):
+    if not _mxu_aligned(bn, bk, bm, transpose_a):
         return "einsum"
     return "pallas"
 
@@ -146,7 +167,10 @@ def local_matmul(a: jnp.ndarray, b: jnp.ndarray, *, out_dtype=None,
                                     transpose_a=transpose_a)
     if isinstance(b, BCOO):
         b = b.todense()         # dense @ sp: right operand densifies
-    mode = gemm_backend(bn, bk, bm, jnp.dtype(a.dtype), backend)
+    if _auto_partitioned(a) or _auto_partitioned(b):
+        # GSPMD partitions the einsum; a pallas_call it cannot partition
+        backend = "einsum"
+    mode = gemm_backend(bn, bk, bm, jnp.dtype(a.dtype), backend, transpose_a)
     _fire("gemm_dispatch", mode=mode)
     _DISPATCHES.inc(f"dispatch_{mode}")
     if mode == "einsum":

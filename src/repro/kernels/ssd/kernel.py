@@ -30,8 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
-
 
 def _ssd_chunk_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref,
                       y_ref, state_ref, cdec_ref, chunk_dec_ref, *,
@@ -116,7 +114,7 @@ def ssd_chunk_padded(
             jax.ShapeDtypeStruct((bh, t, s), x.dtype),
             jax.ShapeDtypeStruct((bh, nc, 1, 128), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

@@ -6,8 +6,8 @@ Multi-pod:  (2, 16, 16) = ("pod", "data", "model") — 512 chips.
 Functions, not module constants: importing this module never touches jax
 device state (required so smoke tests see 1 CPU device).
 
-Mesh construction goes through ``repro.core.compat.make_mesh`` so the same
-call works on jax versions with and without ``axis_types``.
+Meshes are built with ``Auto`` axes: ``jax.make_mesh`` would default to
+``Explicit`` ones, which the sharding rules here are not written for.
 """
 
 from __future__ import annotations
@@ -16,13 +16,13 @@ from typing import Tuple
 
 import jax
 
-from repro.core.compat import AxisType, make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def dp_axes(multi_pod: bool = False) -> Tuple[str, ...]:
@@ -35,4 +35,5 @@ def make_host_mesh(shape: Tuple[int, ...] = None, axes=None):
     if shape is None:
         shape = (1, n) if n > 1 else (1, 1)
         axes = ("data", "model")
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(shape))

@@ -7,8 +7,10 @@ no runtime underneath it, so the resilience has to live in the driver:
 
 1. **classification** (:func:`classify_error`) — *transient* failures
    (device loss, UNAVAILABLE, interconnect hiccups) are worth retrying;
-   *oom* (RESOURCE_EXHAUSTED) is deterministic for the same program but
-   recoverable by running a cheaper program; everything else is
+   *oom* (RESOURCE_EXHAUSTED in device memory) is deterministic for the
+   same program but recoverable by running a cheaper program; a kernel
+   that the compiler refuses (a Mosaic error, or a tile that overflows the
+   kernel's VMEM) is a fault of that kernel, and like everything else is
    *deterministic* — retrying recomputes the same failure, so it raises
    immediately (unlike the seed's ``run_with_restarts``, which burned
    ``max_failures`` restarts on any exception whatsoever);
@@ -57,7 +59,11 @@ OOM = "oom"
 DETERMINISTIC = "deterministic"
 
 # message patterns for errors that arrive as opaque runtime exceptions
-# (jaxlib raises XlaRuntimeError with the gRPC status baked into the text)
+# (jaxlib raises XlaRuntimeError with the gRPC status baked into the text).
+# A kernel's VMEM overflow also reads RESOURCE_EXHAUSTED, but it is a compile
+# error of that kernel's tiling: stepping down the ladder would only hide it
+# behind the einsum rung.
+_KERNEL_COMPILE_PAT = re.compile(r"memory space vmem|\bMosaic\b", re.I)
 _OOM_PAT = re.compile(
     r"RESOURCE_EXHAUSTED|out of memory|\bOOM\b|allocat\w* .*exceed", re.I)
 _TRANSIENT_PAT = re.compile(
@@ -95,6 +101,8 @@ def classify_error(exc: BaseException, default: str = DETERMINISTIC) -> str:
     if isinstance(exc, _DETERMINISTIC_TYPES):
         return DETERMINISTIC
     msg = str(exc)
+    if _KERNEL_COMPILE_PAT.search(msg):
+        return DETERMINISTIC
     if _OOM_PAT.search(msg):
         return OOM
     if _TRANSIENT_PAT.search(msg):

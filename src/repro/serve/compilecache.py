@@ -38,33 +38,20 @@ from repro.serve.batching import BucketSpec, GeometryBucket, \
 class PredictCompileCache:
     """AOT-warmed predict plans for ONE estimator across its bucket set.
 
-    ``donate_inputs`` marks the request-batch leaf of each warmed plan as
-    donatable (``Plan.compile_aot(donate_argnums=...)``): the packed batch
-    is a per-request temporary the dispatcher never reuses, so on
-    accelerators XLA may alias its HBM for the output.  Model-parameter
-    leaves are never donated — they are the fitted state every later
-    request re-binds.  CPU ignores donation, so behavior there is
-    unchanged.
+    The warmed executables donate no input: they sit in the shared
+    structural plan cache, where a direct ``predict`` of a bucket-shaped
+    array and a retry of a failed launch bind them too, and a donated leaf
+    would be the caller's array.
     """
 
-    def __init__(self, estimator, spec: BucketSpec,
-                 donate_inputs: bool = True):
+    def __init__(self, estimator, spec: BucketSpec):
         self.estimator = estimator
         self.spec = spec
-        self.donate_inputs = donate_inputs
         self.plan_backed = estimator.has_predict_plan()
         #: bucket -> structural key of the warmed plan (the cache-hit oracle)
         self.warmed_keys: Dict[GeometryBucket, tuple] = {}
         #: bucket -> the warmed Plan (kept for analysis linting / tests)
         self.plans: Dict[GeometryBucket, _plan.Plan] = {}
-
-    def _donate_argnums(self, p: _plan.Plan, x: DsArray) -> tuple:
-        """Leaf positions holding the representative batch ``x`` — the only
-        buffers a warmed predict executable may consume."""
-        if not self.donate_inputs:
-            return ()
-        return tuple(i for i, leaf in enumerate(p.leaves)
-                     if getattr(leaf, "value", None) is x)
 
     def warm(self) -> int:
         """Record + AOT-compile the predict plan for every declared bucket
@@ -81,7 +68,7 @@ class PredictCompileCache:
                     self.warmed_keys[bucket] = ()
                 continue
             p = self.estimator.predict_plan(x)
-            if p.compile_aot(donate_argnums=self._donate_argnums(p, x)):
+            if p.compile_aot():
                 compiled += 1
             self.warmed_keys[bucket] = p.key
             self.plans[bucket] = p

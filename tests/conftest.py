@@ -1,139 +1,10 @@
-import functools
 import os
 import sys
-import types
 
 # tests see ONE CPU device (the dry-run sets its own 512-device flag in a
 # separate process); repo root on path so `benchmarks` imports resolve.
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-
-
-# ---------------------------------------------------------------------------
-# Optional-hypothesis shim.
-#
-# The property tests use a small slice of the hypothesis API.  When the real
-# package is available (see requirements-dev.txt) it is used untouched; when
-# it is missing we install a deterministic stand-in: each @given test runs
-# against a FIXED example corpus drawn from seeded numpy Generators, so the
-# suite still exercises the same shape/seed diversity reproducibly.
-# ---------------------------------------------------------------------------
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    import numpy as _np
-
-    _SHIM_SEED = 20260725
-
-    class _Strategy:
-        def __init__(self, draw_fn):
-            self._draw_fn = draw_fn
-
-        def draw(self, rng):
-            return self._draw_fn(rng)
-
-    def _integers(lo, hi):
-        return _Strategy(lambda rng: int(rng.integers(lo, hi + 1)))
-
-    def _booleans():
-        return _Strategy(lambda rng: bool(rng.integers(2)))
-
-    def _floats(lo, hi):
-        return _Strategy(lambda rng: float(rng.uniform(lo, hi)))
-
-    def _sampled_from(seq):
-        seq = list(seq)
-        return _Strategy(lambda rng: seq[int(rng.integers(len(seq)))])
-
-    def _lists(elem, min_size=0, max_size=10):
-        def draw(rng):
-            k = int(rng.integers(min_size, max_size + 1))
-            return [elem.draw(rng) for _ in range(k)]
-        return _Strategy(draw)
-
-    def _tuples(*elems):
-        return _Strategy(lambda rng: tuple(e.draw(rng) for e in elems))
-
-    def _just(value):
-        return _Strategy(lambda rng: value)
-
-    def _builds(fn, *elems):
-        return _Strategy(lambda rng: fn(*[e.draw(rng) for e in elems]))
-
-    def _composite(fn):
-        def make(*args, **kwargs):
-            def draw_fn(rng):
-                return fn(lambda s: s.draw(rng), *args, **kwargs)
-            return _Strategy(draw_fn)
-        return make
-
-    class _Settings:
-        _profiles = {"default": {"max_examples": 10}}
-        _active = "default"
-
-        def __init__(self, **kwargs):
-            self._kwargs = kwargs
-
-        def __call__(self, test):  # used as @settings(...) decorator
-            n = self._kwargs.get("max_examples")
-            if n is not None:
-                test._shim_max_examples = n
-            return test
-
-        @classmethod
-        def register_profile(cls, name, **kwargs):
-            cls._profiles[name] = kwargs
-
-        @classmethod
-        def load_profile(cls, name):
-            cls._active = name
-
-        @classmethod
-        def max_examples(cls):
-            return cls._profiles.get(cls._active, {}).get("max_examples", 10)
-
-    def _given(*strategies, **kw_strategies):
-        import inspect
-
-        def deco(test):
-            @functools.wraps(test)
-            def wrapper():
-                n = getattr(test, "_shim_max_examples", None) or _Settings.max_examples()
-                for i in range(n):
-                    rng = _np.random.default_rng(_SHIM_SEED + i)
-                    drawn = [s.draw(rng) for s in strategies]
-                    kdrawn = {k: s.draw(rng) for k, s in kw_strategies.items()}
-                    test(*drawn, **kdrawn)
-            # hide the strategy parameters from pytest's fixture resolution
-            # (real hypothesis does the same signature rewrite)
-            del wrapper.__wrapped__
-            wrapper.__signature__ = inspect.Signature()
-            return wrapper
-        return deco
-
-    _st = types.ModuleType("hypothesis.strategies")
-    _st.integers = _integers
-    _st.booleans = _booleans
-    _st.floats = _floats
-    _st.sampled_from = _sampled_from
-    _st.lists = _lists
-    _st.tuples = _tuples
-    _st.just = _just
-    _st.builds = _builds
-    _st.composite = _composite
-
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.given = _given
-    _hyp.settings = _Settings
-    _hyp.strategies = _st
-    _hyp.HealthCheck = types.SimpleNamespace(too_slow="too_slow",
-                                             data_too_large="data_too_large")
-    _hyp.assume = lambda cond: None
-    _hyp.__is_shim__ = True
-
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st
 
 
 # ---------------------------------------------------------------------------

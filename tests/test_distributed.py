@@ -33,10 +33,10 @@ def test_shmap_collective_ops():
     out = run_subprocess("""
         import numpy as np, jax
         from repro.core import from_array
-        from repro.core.compat import make_mesh
+        from jax.sharding import Mesh
         from repro.core.shmap_ops import (summa_matmul, cannon_matmul,
                                           transpose_pp, colsum_psum)
-        mesh = make_mesh((2, 2), ("data", "model"))
+        mesh = Mesh(np.array(jax.devices()).reshape(2, 2), ("data", "model"))
         rng = np.random.default_rng(0)
         x = rng.normal(size=(32, 48)).astype(np.float32)
         y = rng.normal(size=(48, 24)).astype(np.float32)
@@ -64,16 +64,15 @@ def test_shmap_collective_ops():
 def test_compressed_psum_unbiased():
     out = run_subprocess("""
         import numpy as np, jax, jax.numpy as jnp
-        from jax.sharding import PartitionSpec as P
-        from repro.core.compat import make_mesh, shard_map
+        from jax.sharding import Mesh, PartitionSpec as P
         from repro.distributed import compressed_psum
-        mesh = make_mesh((4,), ("pod",))
+        mesh = Mesh(np.array(jax.devices()), ("pod",))
         x = np.random.default_rng(0).normal(size=(4, 64)).astype(np.float32)
 
         def body(xs, key):
             return compressed_psum(xs[0], "pod", key[0], 4)
 
-        f = shard_map(body, mesh=mesh, in_specs=(P("pod", None), P("pod")),
+        f = jax.shard_map(body, mesh=mesh, in_specs=(P("pod", None), P("pod")),
                       out_specs=P(None), check_vma=False)
         keys = jax.random.split(jax.random.PRNGKey(0), 4)
         errs = []
@@ -114,8 +113,8 @@ def test_sharded_train_step_runs_and_matches():
         # single-device reference
         _, m_ref = make_train_step(model, opt)(state, batch)
 
-        from repro.core.compat import make_mesh
-        mesh = make_mesh((2, 2), ("data", "model"))
+        from jax.sharding import Mesh
+        mesh = Mesh(np.array(jax.devices()).reshape(2, 2), ("data", "model"))
         env = cm.ShardEnv(mesh=mesh, dp=("data",), tp="model")
         ps = shlib.param_shardings(state.params, mesh)
         osh = shlib.opt_state_shardings(state.opt_state, state.params, mesh)
@@ -138,15 +137,14 @@ def test_elastic_checkpoint_reshard():
     """Save on a 4-device mesh, restore onto a 2-device mesh."""
     out = run_subprocess("""
         import tempfile, numpy as np, jax, jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
         from repro.checkpoint import save, restore
-        from repro.core.compat import make_mesh
-        mesh4 = make_mesh((4,), ("data",))
+        mesh4 = Mesh(np.array(jax.devices()), ("data",))
         x = jnp.arange(64.0).reshape(8, 8)
         xs = jax.device_put(x, NamedSharding(mesh4, P("data", None)))
         with tempfile.TemporaryDirectory() as d:
             save(d, 0, {"x": xs})
-            mesh2 = make_mesh((2,), ("data",), devices=jax.devices()[:2])
+            mesh2 = Mesh(np.array(jax.devices()[:2]), ("data",))
             sh = {"x": NamedSharding(mesh2, P(None, "data"))}
             out = restore(d, 0, {"x": jnp.zeros((8, 8))}, sh)
             assert np.allclose(np.asarray(out["x"]), np.asarray(x))
@@ -157,10 +155,11 @@ def test_elastic_checkpoint_reshard():
 
 
 def test_sharding_rules_sanitize():
-    from jax.sharding import PartitionSpec as P
-    from repro.core.compat import make_mesh
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh, PartitionSpec as P
     from repro.distributed.sharding import sanitize_spec
-    mesh = make_mesh((1,), ("model",))
+    mesh = Mesh(np.array(jax.devices()[:1]), ("model",))
     # 7 not divisible by any mesh>1 — with size-1 mesh everything divides
     assert sanitize_spec(P("model", None), (7, 3), mesh) == P("model", None)
 
@@ -170,11 +169,10 @@ def test_structural_ops_preserve_sharding():
     (the seed materialize path silently collapsed to single-device)."""
     out = run_subprocess("""
         import numpy as np, jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
         from repro.core import concat_rows, from_array
-        from repro.core.compat import make_mesh
 
-        mesh = make_mesh((2, 2), ("data", "model"))
+        mesh = Mesh(np.array(jax.devices()).reshape(2, 2), ("data", "model"))
         x = np.random.default_rng(0).normal(size=(64, 64)).astype(np.float32)
         A = from_array(x, (8, 8)).distribute(mesh)
         want = NamedSharding(mesh, P("data", "model", None, None))
@@ -196,6 +194,35 @@ def test_structural_ops_preserve_sharding():
         f = A[np.arange(1, 64, 2)]               # gather filtering
         assert np.allclose(np.asarray(f.collect()), x[1::2])
         assert f.blocks.sharding == want, f.blocks.sharding
+        print("OK")
+    """, devices=4)
+    assert "OK" in out
+
+
+def test_distributed_matmul_takes_partition_safe_path():
+    """A Mosaic kernel cannot be partitioned automatically: with the TPU
+    dispatch branch steered on, ``@`` on distributed operands must still
+    take the einsum (SPMD partitions it) and match NumPy."""
+    out = run_subprocess("""
+        import numpy as np, jax
+        from jax.sharding import Mesh
+        from repro import obs
+        from repro.core import from_array
+        from repro.kernels.matmul import ops
+        ops.jax.default_backend = lambda: "tpu"   # the chip's branch
+        mesh = Mesh(np.array(jax.devices()).reshape(2, 2), ("data", "model"))
+        rng = np.random.default_rng(0)
+        x = rng.normal(size=(256, 256)).astype(np.float32)
+        y = rng.normal(size=(256, 256)).astype(np.float32)
+        A = from_array(x, (128, 128)).distribute(mesh)
+        B = from_array(y, (128, 128)).distribute(mesh)
+        assert ops.gemm_backend(128, 128, 128, np.dtype(np.float32)) == "pallas"
+        obs.reset_all()
+        C = A @ B
+        snap = obs.snapshot("gemm")
+        assert snap["gemm.dispatch_einsum"] == 1, snap
+        assert snap["gemm.dispatch_pallas"] == 0, snap
+        assert np.allclose(np.asarray(C.collect()), x @ y, atol=1e-3)
         print("OK")
     """, devices=4)
     assert "OK" in out

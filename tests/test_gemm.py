@@ -48,16 +48,29 @@ def test_stacked_matmul_sweep(gi, gk, gj, bn, bk, bm, dtype):
                                _einsum_ref(a, b), atol=tol, rtol=tol)
 
 
-@pytest.mark.parametrize("tiles", [(4, 4, 4), (8, 4, 2), (2, 8, 8)])
+@pytest.mark.parametrize("tiles", [(8, 128, 128), (16, 128, 256),
+                                   (24, 256, 128)])
 def test_stacked_matmul_subtiling(tiles):
-    """block dims > tile targets split into Pallas grid steps when they divide."""
+    """block dims > tile targets split into Pallas grid steps by their
+    largest aligned divisor (48 rows -> 8/16/24, 256 lanes -> 128)."""
     tm, tn, tk = tiles
-    a = RNG.normal(size=(2, 3, 8, 8)).astype(np.float32)
-    b = RNG.normal(size=(3, 2, 8, 8)).astype(np.float32)
+    a = RNG.normal(size=(2, 3, 48, 256)).astype(np.float32)
+    b = RNG.normal(size=(3, 2, 256, 256)).astype(np.float32)
     out = stacked_matmul(jnp.asarray(a), jnp.asarray(b), block_m=tm,
                          block_n=tn, block_k=tk, interpret=True)
     np.testing.assert_allclose(np.asarray(out, np.float64),
                                _einsum_ref(a, b), atol=1e-3, rtol=1e-3)
+
+
+@pytest.mark.parametrize("dim,unit,tile", [
+    (512, 8, 512), (300, 8, 300),         # at most the target: whole
+    (8192, 8, 512), (10000, 8, 400), (12000, 8, 480), (1000, 8, 200),
+    (1152, 128, 384), (640, 128, 128),    # lane dims: multiples of 128
+    (1000, 128, 1000),                    # no aligned divisor: whole
+])
+def test_pick_tile_fits_vmem(dim, unit, tile):
+    from repro.kernels.matmul.kernel import _pick_tile
+    assert _pick_tile(dim, 512, unit) == tile
 
 
 def test_local_matmul_backends_agree():
@@ -95,6 +108,28 @@ def test_matmul_ta_matches_dense():
     np.testing.assert_allclose(np.asarray(c.collect()), x.T @ y,
                                atol=2e-3, rtol=1e-3)
     assert c.pad_state.kind == "zero"
+
+
+def test_gemm_backend_never_pallas_for_f16(monkeypatch):
+    """Mosaic refuses the f16 kernel ("Invalid vector type for load"), so
+    even on a TPU with aligned blocks f16 takes einsum; f32/bf16 do not."""
+    monkeypatch.delenv("REPRO_GEMM", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert gemm_backend(512, 512, 512, jnp.dtype(jnp.float16)) == "einsum"
+    assert gemm_backend(512, 512, 512, jnp.dtype(jnp.float32)) == "pallas"
+    assert gemm_backend(512, 512, 512, jnp.dtype(jnp.bfloat16)) == "pallas"
+
+
+def test_gemm_backend_alignment_follows_orientation(monkeypatch):
+    """Under transpose_a the contraction dim sits on A's sublanes, so a
+    dislib-style 10,000-row block is aligned there and not otherwise."""
+    monkeypatch.delenv("REPRO_GEMM", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    f32 = jnp.dtype(jnp.float32)
+    assert gemm_backend(512, 10000, 512, f32, transpose_a=True) == "pallas"
+    assert gemm_backend(512, 10000, 512, f32) == "einsum"
+    assert gemm_backend(1000, 512, 512, f32, transpose_a=True) == "einsum"
+    assert gemm_backend(1000, 512, 512, f32) == "pallas"
 
 
 def test_gemm_backend_policy(monkeypatch):

@@ -124,6 +124,22 @@ def test_classify_error_taxonomy():
     assert ce(RuntimeError("boom"), default=R.TRANSIENT) == R.TRANSIENT
 
 
+def test_classify_kernel_compile_errors_deterministic():
+    """A kernel the TPU compiler refuses must raise, not walk the ladder
+    down to einsum: only device-memory exhaustion is OOM."""
+    ce = R.classify_error
+    vmem = RuntimeError(
+        "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem while "
+        "allocating on stack for %stacked_matmul.1 = f32[2,1,12000,512]")
+    mosaic = RuntimeError("INTERNAL: Mosaic failed to compile TPU kernel: "
+                          "Invalid vector type for load")
+    hbm = RuntimeError("RESOURCE_EXHAUSTED: Out of memory while trying to "
+                       "allocate 17179869184 bytes.")
+    assert ce(vmem) == R.DETERMINISTIC
+    assert ce(mosaic) == R.DETERMINISTIC
+    assert ce(hbm) == R.OOM
+
+
 # ---------------------------------------------------------------------------
 # run_resilient: retry + degradation ladder
 # ---------------------------------------------------------------------------

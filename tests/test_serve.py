@@ -522,45 +522,22 @@ def test_future_timeout():
 
 
 # ---------------------------------------------------------------------------
-# AOT input donation (Plan.compile_aot donate_argnums)
+# No warmed executable consumes its inputs
+#
+# Warmed executables live in the shared structural plan cache, so a direct
+# ``predict`` whose input has a warmed bucket's geometry binds the same
+# executable.  One that donated its batch leaf would delete the caller's
+# array there (and a retry would bind a deleted buffer), so none donates.
 # ---------------------------------------------------------------------------
 
 
-def test_compile_aot_accepts_donate_argnums(ridge):
-    """Regression: ``Plan.compile_aot`` had no ``donate_argnums`` — the
-    serve warm path could not mark the packed request batch donatable, so
-    on accelerators every predict paid an extra output allocation."""
-    plan_mod.clear_cache()
-    x = from_array(jnp.asarray(_rows(4)), (4, N_FEATURES))
-    p = ridge.predict_plan(x)
-    donate = tuple(i for i, leaf in enumerate(p.leaves)
-                   if getattr(leaf, "value", None) is x)
-    assert donate, "the batch leaf must appear in the plan's leaves"
-    assert p.compile_aot(donate_argnums=donate) is True
-    # idempotent: the donated executable is cached under the same key
-    assert p.compile_aot(donate_argnums=donate) is False
-
-
 def test_donated_warm_serving_output_unchanged(ridge):
-    """With ``donate_inputs=True`` (the register default), the warmed
-    executables consume the packed batch — served outputs stay bitwise
-    equal to direct predict and the steady-state stream still adds zero
-    plan-cache misses / opt runs / AOT compiles."""
-    from repro.serve.compilecache import representative_input
-
+    """Warmed executables take no donation: served outputs stay bitwise
+    equal to direct predict, the steady-state stream adds zero plan-cache
+    misses / opt runs / AOT compiles, and no compiled executable aliases an
+    input buffer to its output."""
     plan_mod.clear_cache()
     reg = _registry(ridge)
-    model = reg.get("m")
-    assert model.cache.donate_inputs
-    # the donation map finds the batch leaf for every declared bucket
-    for bucket in model.cache.spec.buckets():
-        x = representative_input(bucket)
-        p = ridge.predict_plan(x)
-        assert model.cache._donate_argnums(p, x) != ()
-        # never the fitted-parameter leaves: only leaves holding x itself
-        for i in model.cache._donate_argnums(p, x):
-            assert p.leaves[i].value is x
-
     srv = serve.PredictServer(reg)
     warm = plan_mod.cache_stats()
     batches, served = [], []
@@ -574,6 +551,8 @@ def test_donated_warm_serving_output_unchanged(ridge):
     assert after["misses"] == warm["misses"]
     assert after["opt_runs"] == warm["opt_runs"]
     assert after["aot_compiles"] == warm["aot_compiles"]
+    for p in reg.warmed_plans():
+        assert "input_output_alias" not in plan_mod._CACHE[p.key].as_text()
     # direct predict runs at natural geometry (own plans), so only after
     # the frozen-stats window closes
     for rows, got in zip(batches, served):
@@ -586,10 +565,26 @@ def test_donation_opt_out_warms_without_aliasing(ridge):
 
     plan_mod.clear_cache()
     spec = BucketSpec(N_FEATURES, batch_sizes=(4,), block_rows=4)
-    cache = PredictCompileCache(ridge, spec, donate_inputs=False)
-    bucket = spec.buckets()[0]
-    x = representative_input(bucket)
-    p = ridge.predict_plan(x)
-    assert cache._donate_argnums(p, x) == ()
+    cache = PredictCompileCache(ridge, spec)
     assert cache.warm() == 1
     assert cache.warm() == 0
+    x = representative_input(spec.buckets()[0])
+    assert "input_output_alias" not in \
+        plan_mod._CACHE[ridge.predict_plan(x).key].as_text()
+
+
+def test_direct_predict_of_bucket_shape_keeps_caller_input():
+    """One feature makes the predict output ``(gn, 1, bn, 1)`` blocks, the
+    batch's own shape, so a donating executable could alias the batch.  A
+    direct predict of a bucket-shaped input binds the warmed executable (same
+    plan key) and must leave the caller's array alive for a second call."""
+    est = _fit_ridge(m=1)
+    plan_mod.clear_cache()
+    reg = _registry(est, batch_sizes=(4,))
+    x = from_array(jnp.asarray(_rows(4, m=1)), (4, 1))
+    bucket = reg.get("m").spec.buckets()[0]
+    assert est.predict_plan(x).key == reg.get("m").cache.warmed_keys[bucket]
+    first = np.asarray(est.predict(x).collect())
+    assert not x.blocks.is_deleted()
+    second = np.asarray(est.predict(x).collect())
+    assert np.array_equal(first, second)

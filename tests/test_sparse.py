@@ -181,6 +181,31 @@ def test_elementwise_policy_and_values():
                                        rtol=1e-5, atol=1e-5, err_msg=label)
 
 
+@pytest.mark.parametrize("case", ["square", "overlap", "split_entries",
+                                  "lazy_square"])
+def test_pair_multiply_keeps_lhs_capacity(case):
+    """sparse * sparse lands in the left operand's slots (an all-pairs
+    product would hold nse_a * nse_b per block); entries split across
+    duplicate slots by an index-merge add are summed before multiplying."""
+    x, _, s = mk_sparse(16, 12, 8, 6, density=0.5)
+    y, _, t = mk_sparse(16, 12, 8, 6, density=0.5)
+    lhs, rhs, want = {
+        "square": (s, s, x * x),
+        "overlap": (s, t, x * y),
+        "split_entries": (s + t, t, (x + y) * y),
+        "lazy_square": (s.lazy(), s.lazy(), x * x),
+    }[case]
+    out = lhs * rhs
+    if case.startswith("lazy"):
+        out = out.compute()
+    assert out.block_format == "bcoo"
+    out.check_invariants()
+    assert out.blocks.nse == (lhs.compute() if case.startswith("lazy")
+                              else lhs).blocks.nse
+    np.testing.assert_allclose(np.asarray(out.collect()), want,
+                               rtol=1e-5, atol=1e-6)
+
+
 def test_mixed_format_with_mismatched_blocks():
     """A block-shape mismatch makes alignment rechunk — which densifies a
     sparse operand — and the dispatch must then take the dense path (the

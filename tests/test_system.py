@@ -88,3 +88,40 @@ def test_grad_accumulation_equivalence():
     _, m1 = make_train_step(model, opt, accum_steps=1)(s0, batch)
     _, m2 = make_train_step(model, opt, accum_steps=2)(s0, batch)
     assert abs(float(m1["loss"]) - float(m2["loss"])) < 5e-3
+
+
+@pytest.mark.parametrize("env_dir", [False, True])
+def test_enable_compile_cache_directory(tmp_path, env_dir):
+    """Importing ``repro`` sets no cache; ``enable_compile_cache`` keeps the
+    directory ``JAX_COMPILATION_CACHE_DIR`` names where it is set, and
+    otherwise sets the fixed directory it is given, which a compile then
+    fills."""
+    import subprocess
+    import sys
+    import textwrap
+
+    given, named = tmp_path / "given", tmp_path / "named"
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(named)
+    code = textwrap.dedent(f"""
+        import jax, jax.numpy as jnp
+        import repro
+        before = jax.config.jax_compilation_cache_dir
+        used = repro.enable_compile_cache({str(given)!r})
+        jax.jit(lambda v: v * 2 + 1)(jnp.arange(4.0)).block_until_ready()
+        print(before, used, jax.config.jax_compilation_cache_dir)
+    """)
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["PYTHONPATH"] = os.path.abspath(src)
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr
+    before, used, config = proc.stdout.split()
+    want = named if env_dir else given
+    assert used == config == str(want)
+    assert before == (str(named) if env_dir else "None")
+    assert any(want.iterdir())
+    if env_dir:
+        assert not given.exists()
