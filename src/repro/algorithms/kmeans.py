@@ -27,6 +27,7 @@ from repro.core.dsarray import DsArray, from_array
 from repro.core.dataset_baseline import Dataset
 from repro.estimators.base import BaseEstimator, _FitCheckpoint, \
     _fire, _iter_span
+from repro.obs import tracing as _tracing
 
 
 # The distance and centre-sum contractions run at full f32 precision: XLA's
@@ -249,17 +250,20 @@ class KMeans(BaseEstimator):
         # computed by one fused lazy plan (was re-derived every iteration);
         # for BCOO blocks the lazy plan is the sparse x*x -> row-sum pair,
         # and the init + Lloyd contractions below never densify x
-        x_sq = _row_sq_norms(x)
+        with _tracing.span("fit.row_norms"):
+            x_sq = _row_sq_norms(x)
         # block-native k-means++ init (k D² passes, each one fused op over the
         # stacked tensor; no x.collect() — the array never leaves the devices)
-        init = _kmeanspp_init_ds(x, self.n_clusters,
-                                 np.random.default_rng(self.seed), row_valid,
-                                 x_sq)
+        with _tracing.span("fit.init"):
+            init = _kmeanspp_init_ds(x, self.n_clusters,
+                                     np.random.default_rng(self.seed),
+                                     row_valid, x_sq)
         if checkpoint_dir is None and resume is None:
             # clean path: the device-resident jitted while_loop, untouched —
             # the iterations live inside ONE launch, so the trace gets one
-            # fit.loop span instead of per-iteration fit.iteration spans
-            from repro.obs import tracing as _tracing
+            # fit.loop span instead of per-iteration fit.iteration spans.
+            # The span times the loop's dispatch; the loop's device time is
+            # the _kmeans_run program on the device trace
             with _tracing.span("fit.loop", estimator=type(self).__name__,
                                max_iter=self.max_iter):
                 centers, _, iters = _kmeans_run(x.blocks, init, row_valid,

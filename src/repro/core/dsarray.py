@@ -208,15 +208,26 @@ entry point                 what it does
                               ``obs.summary()`` renders the same spans as an
                               aggregated terminal tree
 ``obs.span/@obs.traced``    the span primitives the instrumented sites use;
-                              spans fence with ``block_until_ready`` so they
-                              time device work, not dispatch.  Span names:
+                              each span is also a profiler
+                              ``TraceAnnotation`` under its bare name, so it
+                              sits on the device trace's clock; spans never
+                              wait for the device (``plan.launch`` times the
+                              dispatch).  Span names: ``plan.build`` /
                               ``plan.optimize`` / ``plan.aot_compile`` /
-                              ``plan.launch``; ``fit.loop`` /
-                              ``fit.iteration``; ``resilience.rung`` (one per
-                              attempt, failures tagged ``error=``);
-                              ``serve.submit`` / ``serve.batch`` /
-                              ``serve.dispatch`` / ``serve.slice``;
-                              ``ingest.load`` / ``ingest.chunk``
+                              ``plan.launch``; ``fit.validate`` /
+                              ``fit.stats`` / ``fit.collect`` /
+                              ``fit.solve`` (linear models),
+                              ``fit.row_norms`` / ``fit.init`` /
+                              ``fit.loop`` (KMeans), ``fit.iteration``;
+                              ``resilience.rung`` (one per attempt, failures
+                              tagged ``error=``); ``serve.submit`` (with its
+                              request id) / ``serve.batch`` / ``serve.plan``
+                              / ``serve.dispatch`` (with its batch id) /
+                              ``serve.slice``; ``ingest.load`` /
+                              ``ingest.chunk``
+``obs.record``              an interval known after the fact, buffered
+                              only: ``serve.queue``, a request's wait from
+                              submit to its first dispatch
 ``obs.registry``            the process-wide Counter/Gauge/Histogram
                               registry; ``plan.cache_stats()``,
                               ``resilience.stats()`` and ``serve.stats()``

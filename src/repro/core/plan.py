@@ -44,7 +44,9 @@ structure + nse like any other.
 from __future__ import annotations
 
 import contextlib
+import hashlib
 import os
+import re
 import sys
 from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -54,7 +56,7 @@ import jax
 from repro.core import expr as _expr
 from repro.core.dsarray import DsArray
 from repro.core.expr import (ArrayLeaf, Blockwise, Expr, Leaf, MatMul,
-                             Transpose, _is_ds, _is_sparse)
+                             Reduce, Transpose, _is_ds, _is_sparse)
 from repro.obs import metrics as _metrics
 from repro.obs import tracing as _tracing
 
@@ -372,6 +374,35 @@ def _preopt_key(roots: Sequence[Expr]) -> Tuple[tuple, List[Expr]]:
     return (tuple(entries), rids), leaves
 
 
+_NAME_MAX = 64
+
+
+def _op_tag(node: Expr) -> str:
+    """A node's op kind for a program name: ``matmul``/``matmul_ta``, a
+    reduction's op, a Blockwise's recorded op (a fused chain's outermost),
+    else the leading tag of its structural key."""
+    if isinstance(node, MatMul):
+        return "matmul_ta" if node.transpose_a else "matmul"
+    if isinstance(node, Reduce):
+        return str(node.op)
+    tag = node.key if isinstance(node, Blockwise) else node.local_key()
+    while isinstance(tag, tuple) and tag:
+        tag = tag[1] if tag[0] in ("fused", "hoistT") else tag[0]
+    return tag if isinstance(tag, str) else node.kind.lower()
+
+
+def program_name(roots: Sequence[Expr]) -> str:
+    """``plan_<op>_<op>...`` from the roots' op kinds: a valid identifier,
+    the same in every process (no ``hash()``), at most ``_NAME_MAX``
+    characters — a longer one keeps its head and a digest of the whole."""
+    name = "_".join(["plan"] + [_op_tag(r) for r in roots])
+    name = re.sub(r"\W+", "_", name).strip("_")
+    if len(name) > _NAME_MAX:
+        digest = hashlib.blake2s(name.encode(), digest_size=4).hexdigest()
+        name = f"{name[:_NAME_MAX - 9].rstrip('_')}_{digest}"
+    return name
+
+
 # LRU-bounded: structural keys can embed user fn objects (map_blocks), so a
 # loop that records a FRESH lambda per iteration would otherwise grow the
 # cache — and pin each jitted executable + closure — without bound.
@@ -441,21 +472,22 @@ class Plan:
         self.stats: Dict[str, int]
         self._raw_roots = list(roots)
         self._roots: Optional[List[Expr]] = None
-        pre_key = None
-        raw_leaves: List[Expr] = []
-        try:
-            pre_key, raw_leaves = _preopt_key(self._raw_roots)
-            cached = _OPT_CACHE.get(pre_key)
-        except TypeError:            # unhashable static param: no caching
-            cached = None
-        if cached is not None:
-            _OPT_CACHE.move_to_end(pre_key)
-            _STATS.inc("opt_skips")
-            self.key, positions, stats = cached
-            self.stats = dict(stats)
-            self.leaves = [raw_leaves[p] for p in positions]
-        else:
-            self._optimize_now(pre_key, raw_leaves)
+        with _tracing.span("plan.build"):
+            pre_key = None
+            raw_leaves: List[Expr] = []
+            try:
+                pre_key, raw_leaves = _preopt_key(self._raw_roots)
+                cached = _OPT_CACHE.get(pre_key)
+            except TypeError:        # unhashable static param: no caching
+                cached = None
+            if cached is not None:
+                _OPT_CACHE.move_to_end(pre_key)
+                _STATS.inc("opt_skips")
+                self.key, positions, stats = cached
+                self.stats = dict(stats)
+                self.leaves = [raw_leaves[p] for p in positions]
+            else:
+                self._optimize_now(pre_key, raw_leaves)
         if _PLAN_OBSERVERS:
             for cb in list(_PLAN_OBSERVERS):
                 cb(self)
@@ -518,6 +550,9 @@ class Plan:
 
             return tuple(ev(r) for r in detached)
 
+        # jit names its program after the function: the device trace's
+        # module line then tells one plan's program from another's
+        run.__name__ = run.__qualname__ = program_name(self.roots)
         return run
 
     def leaf_values(self) -> List:
@@ -564,6 +599,10 @@ class Plan:
         return True
 
     def execute(self) -> tuple:
+        """Launch the compiled plan (compiling it on a cache miss) and
+        return its outputs without waiting for the device.  While tracing
+        is on, the ``plan.launch`` span around the call times the host's
+        dispatch only."""
         _fire("plan_execute", mode="fused")
         compiled = _CACHE.get(self.key)
         cached = compiled is not None
@@ -578,14 +617,13 @@ class Plan:
             _CACHE.move_to_end(self.key)
         _STATS.inc("launches")
         if _tracing.enabled():
-            # fence inside the span so it measures device work, not async
-            # dispatch; the disabled path below stays byte-identical
+            # the span times the host's dispatch, not the device's work
+            # (the profiler's device planes have that); the branch keeps
+            # the span's kwargs off the disabled path
             with _tracing.span("plan.launch", mode="fused", cached=cached,
                                inputs=len(self.leaves)):
                 with _expr.suspend_lazy():
-                    out = compiled(*self.leaf_values())
-                jax.block_until_ready(out)
-            return out
+                    return compiled(*self.leaf_values())
         with _expr.suspend_lazy():
             return compiled(*self.leaf_values())
 
@@ -607,9 +645,7 @@ class Plan:
         if _tracing.enabled():
             with _tracing.span("plan.launch", mode=backend or "eager",
                                inputs=len(self.leaves)):
-                out = self._run_eager(run, backend)
-                jax.block_until_ready(out)
-            return out
+                return self._run_eager(run, backend)
         return self._run_eager(run, backend)
 
     def _run_eager(self, run, backend: Optional[str]) -> tuple:

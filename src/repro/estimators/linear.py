@@ -33,6 +33,7 @@ import numpy as np
 from repro.core import plan
 from repro.core.dsarray import DsArray, from_array
 from repro.estimators.base import BaseRegressor
+from repro.obs import tracing as _tracing
 from repro.resilience.guards import NumericalDivergence, require_finite_host
 
 # cond(X) beyond which the squared-cond normal equations lose f32 accuracy
@@ -62,16 +63,18 @@ class LinearRegression(BaseRegressor):
         """(XᵀX, Xᵀy, colsums) via one recorded lazy plan: the optimizer
         folds both transposes into ``matmul_ta`` (sparse-native for bcoo)
         and CSE shares the single ``x`` leaf across all three roots."""
-        y_ds = from_array(jnp.asarray(y, jnp.float32).reshape(-1, 1),
-                          (x.block_shape[0], 1))
-        xl = x.lazy()
-        g = xl.T @ x
-        c = xl.T @ y_ds
-        s = xl.sum(axis=0)
-        g_ds, c_ds, s_ds = plan.compute_multi(g, c, s)
-        gram = np.asarray(g_ds.collect(), np.float64)
-        xty = np.asarray(c_ds.collect(), np.float64).ravel()
-        colsum = np.asarray(s_ds.collect(), np.float64).ravel()
+        with _tracing.span("fit.stats"):
+            y_ds = from_array(jnp.asarray(y, jnp.float32).reshape(-1, 1),
+                              (x.block_shape[0], 1))
+            xl = x.lazy()
+            g = xl.T @ x
+            c = xl.T @ y_ds
+            s = xl.sum(axis=0)
+            g_ds, c_ds, s_ds = plan.compute_multi(g, c, s)
+        with _tracing.span("fit.collect"):
+            gram = np.asarray(g_ds.collect(), np.float64)
+            xty = np.asarray(c_ds.collect(), np.float64).ravel()
+            colsum = np.asarray(s_ds.collect(), np.float64).ravel()
         return gram, xty, colsum
 
     def _solve_normal(self, gram, xty, colsum, n, ysum):
@@ -154,7 +157,8 @@ class LinearRegression(BaseRegressor):
             return self._fit(x, y)
 
     def _fit(self, x, y) -> "LinearRegression":
-        x, y = self._validate_fit(x, y)
+        with _tracing.span("fit.validate"):
+            x, y = self._validate_fit(x, y)
         n, m = x.shape
         self.n_features_in_ = m
         if self.solver not in ("auto", "normal", "tsqr"):
@@ -163,6 +167,7 @@ class LinearRegression(BaseRegressor):
         gram = xty = colsum = None
         if solver != "tsqr":
             gram, xty, colsum = self._normal_stats(x, y)
+        with _tracing.span("fit.solve"):
             if solver == "auto" and self.alpha == 0.0 and not x.is_sparse \
                     and n >= m:
                 ev = np.linalg.eigvalsh(gram)
@@ -174,11 +179,12 @@ class LinearRegression(BaseRegressor):
                     solver = "normal"
             elif solver == "auto":
                 solver = "normal"
-        if solver == "tsqr":
-            self.coef_, self.intercept_ = self._solve_tsqr(x, y)
-        else:
-            self.coef_, self.intercept_ = self._solve_normal(
-                gram, xty, colsum, n, float(np.asarray(y, np.float64).sum()))
+            if solver == "tsqr":
+                self.coef_, self.intercept_ = self._solve_tsqr(x, y)
+            else:
+                self.coef_, self.intercept_ = self._solve_normal(
+                    gram, xty, colsum, n,
+                    float(np.asarray(y, np.float64).sum()))
         self.solver_used_ = solver
         return self
 

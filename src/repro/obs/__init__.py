@@ -3,8 +3,13 @@
 One import surface over three concerns:
 
 * **tracing** (:mod:`repro.obs.tracing`) — ``span``/``traced``,
-  off-by-default and allocation-free while off; ``trace_to(path)`` exports
-  Chrome trace-event JSON, ``summary()`` renders the aggregated tree.
+  off-by-default and allocation-free while off.  While on, each span is
+  also a ``jax.profiler.TraceAnnotation`` under its bare name, so it lands
+  on the host plane of a profiler capture on the device trace's clock, and
+  a buffered event carrying its enclosing span (``parent``); ``record``
+  buffers an interval known only after the fact.  ``trace_to(path)``
+  exports Chrome trace-event JSON, ``summary()`` renders the aggregated
+  tree.
 * **metrics** (:mod:`repro.obs.metrics`) — the process-wide
   :data:`registry` of Counter/Gauge/Histogram objects that
   ``plan.cache_stats()``, ``resilience.stats()`` and ``serve.stats()``
@@ -24,8 +29,8 @@ from __future__ import annotations
 from repro.obs.metrics import (Counter, CounterGroup, Gauge, Histogram,
                                MetricsRegistry, registry)
 from repro.obs.tracing import (Span, clear, disable, enable, enabled,
-                               events, span, span_allocations, summary,
-                               trace_to, traced)
+                               events, now_ns, record, span,
+                               span_allocations, summary, trace_to, traced)
 from repro.obs import tracing as _tracing
 
 
@@ -52,7 +57,7 @@ def profile(target, **kwargs):
 
 __all__ = [
     "Counter", "CounterGroup", "Gauge", "Histogram", "MetricsRegistry",
-    "Span", "clear", "disable", "enable", "enabled", "events", "profile",
-    "registry", "reset_all", "snapshot", "span", "span_allocations",
-    "summary", "trace_to", "traced",
+    "Span", "clear", "disable", "enable", "enabled", "events", "now_ns",
+    "profile", "record", "registry", "reset_all", "snapshot", "span",
+    "span_allocations", "summary", "trace_to", "traced",
 ]

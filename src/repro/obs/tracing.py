@@ -2,18 +2,29 @@
 
 Design center: **zero overhead when disabled**.  Tracing is off by
 default; ``span(...)`` then returns one shared :data:`_NULL_SPAN`
-singleton — no object allocation, no clock read, no lock — so the plan
-launch hot path pays a single module-flag check (the same discipline as
-``plan._fire``'s one-dict-lookup fault hook).  The overhead-guard test
-asserts this literally: a 100-launch hot loop with tracing off leaves the
-span-allocation counter at exactly 0.
+singleton — no object allocation, no clock read, no lock, no profiler
+annotation — so the plan launch hot path pays a single module-flag check
+(the same discipline as ``plan._fire``'s one-dict-lookup fault hook).  The
+overhead-guard test asserts this literally: a 100-launch hot loop with
+tracing off leaves the span-allocation counter at exactly 0.
 
-When enabled, each ``with span(name, **attrs):`` block records one Chrome
-trace-event "complete" record (``ph: "X"`` — name, microsecond ``ts`` /
-``dur``, pid/tid, ``args``) into a lock-protected buffer.  Instrumented
-call sites additionally fence device work (``jax.block_until_ready``)
-*inside* their spans — only on the enabled path — so a span over a plan
-launch measures execution, not async dispatch.
+When enabled, each ``with span(name, **attrs):`` block does two things:
+
+* it enters a ``jax.profiler.TraceAnnotation`` under the span's bare
+  name, so the span lands on the host plane of any profiler capture taken
+  meanwhile, on the same clock as the device's operations;
+* it records one Chrome trace-event "complete" record (``ph: "X"`` —
+  name, microsecond ``ts``/``dur``, pid/tid, ``parent``, ``args``) into a
+  lock-protected buffer.  ``ts`` is read from the clock the profiler
+  stamps host events with (``time.time_ns``, the system's real-time
+  clock), so a :func:`trace_to` export lines up with an ``.xplane.pb`` of
+  the same window.  ``parent`` is the name of the enclosing span on the
+  same thread (None at the top), from which self time is computed.
+
+Spans do not wait for the device: a span over a plan launch times the
+host's dispatch of it, and the device's own time is on the profiler's
+device planes.  :func:`record` adds an interval known only after the fact
+(a request's wait in a queue) to the buffer alone.
 
 Exports: :func:`trace_to` writes the events captured inside its block as
 a ``{"traceEvents": [...]}`` JSON file loadable by ``chrome://tracing`` /
@@ -33,12 +44,22 @@ import threading
 import time
 from typing import Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 _lock = threading.Lock()
 _enabled = False
 _events: List[dict] = []       # finished Chrome "X" records, append-only
 _span_allocs = 0               # Span objects created since last clear()
 _MAX_EVENTS = 1_000_000        # hard buffer bound; beyond it, events drop
 _dropped = 0
+
+
+class _Local(threading.local):
+    def __init__(self):
+        self.stack: List[str] = []     # names of the thread's open spans
+
+
+_local = _Local()
 
 
 def enabled() -> bool:
@@ -101,12 +122,47 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
-class Span:
-    """One timed block -> one Chrome "X" event.  Only ever constructed on
-    the enabled path; ``set(**attrs)`` attaches late-known attributes
-    (e.g. cache-hit status discovered mid-block)."""
+def now_ns() -> int:
+    """The tracing clock in nanoseconds: the real-time clock the profiler
+    stamps its host events with.  ``record`` takes its bounds in it."""
+    return time.time_ns()
 
-    __slots__ = ("name", "args", "_t0")
+
+def _append(evt: dict) -> None:
+    global _dropped
+    with _lock:
+        if _enabled:
+            if len(_events) < _MAX_EVENTS:
+                _events.append(evt)
+            else:
+                _dropped += 1
+
+
+def _event(name: str, t0: int, t1: int, parent: Optional[str],
+           args: dict) -> dict:
+    evt = {
+        "name": name,
+        "cat": name.split(".", 1)[0],
+        "ph": "X",
+        "ts": t0 / 1000,               # microseconds, trace-event unit
+        "dur": (t1 - t0) / 1000,
+        "pid": os.getpid(),
+        "tid": threading.get_ident(),
+        "parent": parent,
+    }
+    if args:
+        evt["args"] = {k: _jsonable(v) for k, v in args.items()}
+    return evt
+
+
+class Span:
+    """One timed block -> one profiler annotation and one Chrome "X"
+    event.  Only ever constructed on the enabled path; ``set(**attrs)``
+    attaches late-known attributes (e.g. cache-hit status discovered
+    mid-block), which go to the buffer only: the annotation keeps the bare
+    name."""
+
+    __slots__ = ("name", "args", "_t0", "_note", "_parent")
 
     def __init__(self, name: str, args: Optional[dict] = None):
         global _span_allocs
@@ -114,37 +170,29 @@ class Span:
         self.name = name
         self.args = args or {}
         self._t0 = 0
+        self._note = None
+        self._parent = None
 
     def set(self, **attrs) -> "Span":
         self.args.update(attrs)
         return self
 
     def __enter__(self) -> "Span":
-        self._t0 = time.perf_counter_ns()
+        stack = _local.stack
+        self._parent = stack[-1] if stack else None
+        stack.append(self.name)
+        self._note = TraceAnnotation(self.name)
+        self._note.__enter__()
+        self._t0 = time.time_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        dur_ns = time.perf_counter_ns() - self._t0
-        evt = {
-            "name": self.name,
-            "cat": self.name.split(".", 1)[0],
-            "ph": "X",
-            "ts": self._t0 / 1e3,          # microseconds, trace-event unit
-            "dur": dur_ns / 1e3,
-            "pid": os.getpid(),
-            "tid": threading.get_ident(),
-        }
+        t1 = time.time_ns()
+        self._note.__exit__(exc_type, exc, tb)
+        _local.stack.pop()
         if exc_type is not None:
             self.args["error"] = exc_type.__name__
-        if self.args:
-            evt["args"] = {k: _jsonable(v) for k, v in self.args.items()}
-        global _dropped
-        with _lock:
-            if _enabled:
-                if len(_events) < _MAX_EVENTS:
-                    _events.append(evt)
-                else:
-                    _dropped += 1
+        _append(_event(self.name, self._t0, t1, self._parent, self.args))
         return False
 
 
@@ -173,6 +221,17 @@ def traced(fn=None, *, name: Optional[str] = None, **static_attrs):
     if fn is not None:                       # bare @traced
         return deco(fn)
     return deco
+
+
+def record(name: str, start: int, end: int, **attrs) -> None:
+    """Add one interval known only after the fact — ``start``/``end`` in
+    :func:`now_ns` nanoseconds — to the buffer (never to the profiler),
+    while tracing is enabled.  Its ``parent`` is the innermost span open
+    on the calling thread."""
+    if not _enabled:
+        return
+    stack = _local.stack
+    _append(_event(name, start, end, stack[-1] if stack else None, attrs))
 
 
 @contextlib.contextmanager

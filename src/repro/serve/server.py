@@ -25,11 +25,16 @@ the ``serve_dispatch`` fault site (see ``resilience.inject``):
 Every request updates the ``serve.stats()`` counters (queue depth, batch
 sizes, cache hits, sheds/retries/fallbacks) and the per-request latency
 reservoir — the observability loop the ROADMAP's production story needs.
+While ``repro.obs`` tracing is on, each request gets an id at submit
+(``serve.submit``), each dispatch a batch id (``serve.dispatch``), and
+``serve.queue`` records each request's wait from submit to its first
+dispatch.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import sys
 import threading
 import time
@@ -98,6 +103,8 @@ class _Pending:
     n_rows: int
     fmt: str
     future: PredictFuture
+    rid: Optional[int] = None       # request id, given while tracing
+    batch: Optional[int] = None     # id of its first dispatch, while tracing
 
 
 class PredictServer:
@@ -122,6 +129,8 @@ class PredictServer:
         self._wake = threading.Condition(self._lock)
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
+        self._request_ids = itertools.count()
+        self._batch_ids = itertools.count()
 
     # -- request intake ------------------------------------------------------
     def submit(self, name: str, payload, *,
@@ -129,11 +138,12 @@ class PredictServer:
         """Enqueue one request (rows for ``name``) and return its future.
         Payload validation happens here — a malformed request raises at
         submit instead of poisoning a batch."""
-        with _tracing.span("serve.submit", model=name):
+        rid = next(self._request_ids) if _tracing.enabled() else None
+        with _tracing.span("serve.submit", model=name, request=rid):
             model = self.registry.get(name, version)
             payload, n, fmt = model.normalize(payload)
             pend = _Pending(model=model, payload=payload, n_rows=n, fmt=fmt,
-                            future=PredictFuture())
+                            future=PredictFuture(), rid=rid)
             with self._wake:
                 self._queue.append(pend)
                 _stats.bump("requests")
@@ -192,6 +202,23 @@ class PredictServer:
         self.stop()
 
     # -- serving internals ---------------------------------------------------
+    def _dispatch_id(self, chunk: List[_Pending]) -> Optional[int]:
+        """While tracing: a fresh batch id for a dispatch of ``chunk``, and
+        one ``serve.queue`` interval, from submit to now, for each of its
+        requests that leaves the queue here.  None while tracing is off."""
+        if not _tracing.enabled():
+            return None
+        batch = next(self._batch_ids)
+        end_ns, end = _tracing.now_ns(), time.perf_counter()
+        for p in chunk:
+            if p.batch is None:
+                p.batch = batch
+                start_ns = end_ns - round(
+                    (end - p.future.submitted_at) * 1e9)
+                _tracing.record("serve.queue", start_ns, end_ns,
+                                request=p.rid, batch=batch)
+        return batch
+
     def _dispatch_group(self, model: ServedModel, fmt: str,
                         group: List[_Pending]) -> None:
         """Chunk one (model, format) group by the largest declared bucket
@@ -218,13 +245,14 @@ class PredictServer:
         outs = None
         attempts = 0
         shed = False
+        batch = self._dispatch_id(chunk)
         while True:
             try:
                 # one span per dispatch ATTEMPT — transient retries each
                 # leave their own (error-tagged) span in the trace
                 with _tracing.span("serve.dispatch", mode="batched",
                                    model=model.name, requests=len(chunk),
-                                   attempt=attempts):
+                                   attempt=attempts, batch=batch):
                     _fire("serve_dispatch", mode="batched", model=model.name,
                           requests=len(chunk))
                     outs = self._predict_batched(model, fmt, chunk)
@@ -269,7 +297,8 @@ class PredictServer:
         if x is None:                                   # nse overflow
             return None
         if model.plan_backed:
-            plan, warmed = model.cache.plan_for(x, bucket)
+            with _tracing.span("serve.plan"):
+                plan, warmed = model.cache.plan_for(x, bucket)
             out = run_resilient(plan, policy=self.policy)
             record_cache_outcome(warmed, len(chunk))
         else:
@@ -285,11 +314,12 @@ class PredictServer:
         geometry, transient-retried, failures isolated per request."""
         for p in chunk:
             attempts = 0
+            batch = self._dispatch_id([p])
             while True:
                 try:
                     with _tracing.span("serve.dispatch", mode="single",
                                        model=model.name, requests=1,
-                                       attempt=attempts):
+                                       attempt=attempts, batch=batch):
                         _fire("serve_dispatch", mode="single",
                               model=model.name, requests=1)
                         rows = model.predict_direct(p.payload)

@@ -220,6 +220,202 @@ def test_summary_tree_aggregates_by_name():
     assert "3" in text                               # the launch count
 
 
+def _xplane_events(directory, name):
+    """``(absolute start ns, duration ns)`` of the host-plane events called
+    ``name`` in the profile under ``directory``, and every host event name.
+    The reader gives starts from the profile's start, a real-time stamp."""
+    import glob
+    from jax.profiler import ProfileData
+    path = glob.glob(os.path.join(directory, "**", "*.xplane.pb"),
+                     recursive=True)[0]
+    pd = ProfileData.from_file(path)
+    t0 = next(int(v) for plane in pd.planes for k, v in plane.stats
+              if k == "profile_start_time")
+    host = next(p for p in pd.planes if p.name == "/host:CPU")
+    evs = [ev for line in host.lines for ev in line.events]
+    return ([(t0 + int(ev.start_ns), int(ev.duration_ns)) for ev in evs
+             if ev.name == name], {ev.name for ev in evs})
+
+
+def test_span_lands_on_profiler_host_plane_on_the_buffer_clock(tmp_path):
+    import time
+    import jax
+    obs.enable()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span("unit.probe", k=1):
+            time.sleep(0.005)
+    finally:
+        jax.profiler.stop_trace()
+        obs.disable()
+    found, names = _xplane_events(str(tmp_path), "unit.probe")
+    (evt,) = obs.events()
+    assert len(found) == 1, names
+    # the bare name: no attribute encoding on the annotation
+    assert not any(n.startswith("unit.probe#") for n in names)
+    start, dur = found[0]
+    assert abs(start - evt["ts"] * 1e3) < 1e6          # within 1 ms
+    assert abs(dur - evt["dur"] * 1e3) < 1e6
+    assert evt["args"] == {"k": 1}
+
+
+def test_parent_is_the_enclosing_span_on_the_same_thread():
+    obs.enable()
+    with obs.span("a.outer"):
+        with obs.span("a.inner"):
+            t = obs.now_ns()
+            obs.record("a.after", t - 1000, t)
+        t = threading.Thread(target=lambda: obs.span("b.other").__enter__()
+                             .__exit__(None, None, None))
+        t.start()
+        t.join(10)
+        assert not t.is_alive()
+    with obs.span("a.next"):
+        pass
+    obs.disable()
+    parent = {e["name"]: e["parent"] for e in obs.events()}
+    assert parent == {"a.inner": "a.outer", "a.after": "a.inner",
+                      "a.outer": None, "b.other": None, "a.next": None}
+    after = next(e for e in obs.events() if e["name"] == "a.after")
+    assert after["dur"] == 1.0                       # microseconds
+
+
+def test_record_buffers_only_while_enabled():
+    t = obs.now_ns()
+    obs.record("x.off", t, t + 5)
+    assert obs.events() == []
+    obs.enable()
+    obs.record("x.on", t, t + 5000, request=3)
+    obs.disable()
+    (evt,) = obs.events()
+    assert evt["ts"] == t / 1000 and evt["dur"] == 5.0
+    assert evt["args"] == {"request": 3} and evt["ph"] == "X"
+    assert obs.span_allocations() == 0               # no Span object
+
+
+def test_disabled_tracing_allocates_nothing_over_fits_and_batches():
+    from repro.algorithms import KMeans
+    est = _fit_ridge()
+    rng = np.random.default_rng(7)
+    x = from_array(rng.normal(size=(64, 4)).astype(np.float32), (16, 4))
+    KMeans(n_clusters=3, max_iter=2, seed=0).fit(x)
+    reg = serve.ModelRegistry()
+    reg.register("m", est, batch_sizes=(4,), block_rows=4)
+    srv = serve.PredictServer(reg)
+    rows = rng.normal(size=(2, 8)).astype(np.float32)
+    srv.submit("m", rows)
+    srv.pump()
+    obs.reset_all()
+    assert not obs.enabled()
+    _fit_ridge()
+    KMeans(n_clusters=3, max_iter=2, seed=0).fit(x)
+    for _ in range(100):
+        f = srv.submit("m", rows)
+        srv.pump()
+        f.result()
+    assert serve.stats()["batches"] == 100
+    assert obs.span_allocations() == 0
+    assert obs.events() == []
+
+
+def test_traced_launch_waits_for_no_device(monkeypatch):
+    import jax
+    chain = _six_op_chain(seed=4)
+    chain.compute()
+    waits = []
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: waits.append(x) or x)
+    obs.enable()
+    chain.compute()
+    plan_mod.plan_for(chain).execute_eager()
+    obs.disable()
+    assert waits == []
+    assert [e["name"] for e in obs.events()].count("plan.launch") == 2
+
+
+def test_fit_driver_spans_nest_under_their_stages():
+    from repro.algorithms import KMeans
+    plan_mod.clear_cache()
+    obs.enable()
+    _fit_ridge()
+    rng = np.random.default_rng(8)
+    x = from_array(rng.normal(size=(64, 4)).astype(np.float32), (16, 4))
+    KMeans(n_clusters=3, max_iter=2, seed=0).fit(x)
+    obs.disable()
+    evts = obs.events()
+    names = [e["name"] for e in evts]
+    top = [e["name"] for e in evts if e["parent"] is None]
+    assert top[:4] == ["fit.validate", "fit.stats", "fit.collect",
+                       "fit.solve"]
+    assert top[-3:] == ["fit.row_norms", "fit.init", "fit.loop"]
+    parent = {(e["name"], e["parent"]) for e in evts}
+    # the Gram plan is built under fit.stats and optimized on its miss
+    assert ("plan.build", "fit.stats") in parent
+    assert ("plan.optimize", "plan.build") in parent
+    assert ("plan.launch", "fit.stats") in parent
+    assert ("plan.build", "fit.row_norms") in parent
+    assert names.count("fit.solve") == 1
+
+
+def test_serve_queue_records_carry_request_and_batch_ids():
+    est = _fit_ridge()
+    reg = serve.ModelRegistry()
+    reg.register("m", est, batch_sizes=(4, 16), block_rows=4)
+    srv = serve.PredictServer(reg)
+    rng = np.random.default_rng(9)
+    obs.enable()
+    # 10 requests of 2 rows: two batches under the 16-row bucket
+    futs = [srv.submit("m", rng.normal(size=(2, 8)).astype(np.float32))
+            for _ in range(10)]
+    srv.pump()
+    obs.disable()
+    for f in futs:
+        f.result()
+    evts = obs.events()
+    submits = [e["args"]["request"] for e in evts
+               if e["name"] == "serve.submit"]
+    queues = [e for e in evts if e["name"] == "serve.queue"]
+    dispatches = {e["args"]["batch"]: e for e in evts
+                  if e["name"] == "serve.dispatch"}
+    assert submits == list(range(10))
+    assert sorted(q["args"]["request"] for q in queues) == submits
+    assert len(dispatches) == 2
+    for q in queues:
+        d = dispatches[q["args"]["batch"]]
+        # the wait ends where its dispatch begins
+        assert q["ts"] + q["dur"] <= d["ts"] + 1.0
+        assert q["dur"] > 0
+    sizes = sorted(d["args"]["requests"] for d in dispatches.values())
+    assert sizes == [2, 8]
+    assert [sum(q["args"]["batch"] == b for q in queues)
+            for b in sorted(dispatches)] == [8, 2]
+    assert {"serve.plan", "serve.batch", "serve.slice"} <= _names(evts)
+
+
+def test_plan_programs_are_named_after_their_roots():
+    rng = np.random.default_rng(10)
+    x = from_array(rng.normal(size=(32, 8)).astype(np.float32), (16, 8))
+    y = from_array(rng.normal(size=(32, 1)).astype(np.float32), (16, 1))
+    xl = x.lazy()
+    p = plan_mod.plan_for(xl.T @ x, xl.T @ y, xl.sum(axis=0))
+    name = "plan_matmul_ta_matmul_ta_sum"
+    text = p.lowered().as_text()
+    assert f"@jit_{name}" in text
+    # nothing but the name differs from the body jitted as ``run``
+    run = p._make_run()
+    assert run.__name__ == name
+    run.__name__ = run.__qualname__ = "run"
+    import jax
+    plain = jax.jit(run).lower(*p.leaf_values()).as_text()
+    assert text.replace(name, "run") == plain
+    assert plan_mod.plan_for((x.lazy() * x).sum(axis=1))._make_run() \
+        .__name__ == "plan_sum"
+    long = plan_mod.program_name([p.roots[0]] * 30)
+    assert long == plan_mod.program_name([p.roots[0]] * 30)
+    assert long.isidentifier() and len(long) <= 64
+    assert long != plan_mod.program_name([p.roots[0]] * 31)
+
+
 # ---------------------------------------------------------------------------
 # span coverage: plan / fit / resilience / serve / ingest
 # ---------------------------------------------------------------------------
