@@ -215,8 +215,9 @@ entry point                 what it does
                               dispatch).  Span names: ``plan.build`` /
                               ``plan.optimize`` / ``plan.aot_compile`` /
                               ``plan.launch``; ``fit.validate`` /
-                              ``fit.stats`` / ``fit.collect`` /
-                              ``fit.solve`` (linear models),
+                              ``fit.stats`` / ``fit.factor`` /
+                              ``fit.collect`` / ``fit.solve`` /
+                              ``fit.refine`` (linear models),
                               ``fit.row_norms`` / ``fit.init`` /
                               ``fit.loop`` (KMeans), ``fit.iteration``;
                               ``resilience.rung`` (one per attempt, failures
@@ -232,7 +233,9 @@ entry point                 what it does
                               registry; ``plan.cache_stats()``,
                               ``resilience.stats()`` and ``serve.stats()``
                               are views over it (metric names ``plan.*``,
-                              ``resilience.*``, ``serve.*``, ``gemm.*`` —
+                              ``resilience.*``, ``serve.*``, ``gemm.*``,
+                              ``solve.*`` (the linear models' device
+                              solves, refinement steps, host fallbacks) —
                               all increments locked, safe from server
                               worker threads)
 ``obs.snapshot()``          flat ``{metric: value}`` across the registry

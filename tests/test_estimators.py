@@ -516,3 +516,102 @@ def test_linreg_rank_deficient_min_norm():
         pred = np.asarray(est.predict(xd).collect()).ravel()
         np.testing.assert_allclose(pred, y, atol=1e-3,
                                    err_msg=est.solver_used_)
+
+
+# ---------------------------------------------------------------------------
+# The normal equations on the device: float32 Cholesky, float64 refinement
+# ---------------------------------------------------------------------------
+
+def _fit_both(monkeypatch, make, xd, y):
+    """``make()`` fitted on the host LU and then on the device path (the
+    order threshold patched low), with the solve counters' rise."""
+    from repro.estimators import linear
+    monkeypatch.setattr(linear, "_DEVICE_SOLVE_MIN", 10 ** 9)
+    host = make().fit(xd, y)
+    monkeypatch.setattr(linear, "_DEVICE_SOLVE_MIN", 1)
+    before = repro.obs.snapshot("solve")
+    dev = make().fit(xd, y)
+    after = repro.obs.snapshot("solve")
+    return host, dev, {k: after[k] - before.get(k, 0) for k in after}
+
+
+def _theta(est):
+    return np.append(est.coef_, est.intercept_)
+
+
+@pytest.mark.parametrize("sparse", [False, True], ids=["dense", "sparse"])
+@pytest.mark.parametrize("fit_intercept", [True, False])
+@pytest.mark.parametrize("cls, alpha", [(Ridge, 2.0), (LinearRegression, 0.0)])
+@pytest.mark.parametrize("m", [17, 130, 300])
+def test_device_solve_matches_the_host_lu(m, cls, alpha, fit_intercept,
+                                          sparse, monkeypatch):
+    rng = np.random.default_rng(m)
+    n = 4 * m + 50
+    x = (rng.normal(size=(n, m)) + 0.3).astype(np.float32)
+    y = (x @ rng.normal(size=m) + rng.normal(size=n)).astype(np.float32)
+    xd = from_array(x, (64, 64))
+    if sparse:
+        xd = xd.tosparse()
+    host, dev, rose = _fit_both(
+        monkeypatch,
+        lambda: cls(alpha=alpha, fit_intercept=fit_intercept), xd, y)
+    assert host.solver_used_ == dev.solver_used_ == "normal"
+    ref = _theta(host)
+    err = np.linalg.norm(_theta(dev) - ref) / np.linalg.norm(ref)
+    assert err <= 1e-10, err
+    assert rose["solve.device"] == 1 and rose["solve.host_fallback"] == 0
+    assert rose["solve.refine_steps"] >= 1
+
+
+def _cond_1e10(m=12, n=200, seed=0):
+    rng = np.random.default_rng(seed)
+    u, _ = np.linalg.qr(rng.normal(size=(n, m)))
+    v, _ = np.linalg.qr(rng.normal(size=(m, m)))
+    x = (u * np.logspace(0, -5, m)) @ v.T           # cond(XᵀX) = 1e10
+    y = x @ rng.normal(size=m) + 1e-3 * rng.normal(size=n)
+    return x.astype(np.float32), y.astype(np.float32)
+
+
+@pytest.mark.parametrize("case", ["rank_1", "cond_1e10", "no_progress"])
+def test_device_solve_falls_back_to_the_host_lu(case, monkeypatch):
+    """A non-finite float32 factor (a singular Gram; cond 1e10, beyond
+    float32) or a refinement whose backward error stops falling (here a
+    correction of the wrong sign) ends on the host path, as before."""
+    from repro.estimators import linear
+    if case == "no_progress":
+        solve = linear._factor_solve
+        monkeypatch.setattr(linear, "_factor_solve",
+                            lambda chol, r: -solve(chol, r))
+        x, y, _ = regression_data(seed=5, n=200, m=12)
+        make = lambda: Ridge(alpha=1.0)                     # noqa: E731
+    elif case == "rank_1":
+        # as test_linear_solver_divergence_falls_back: a singular Gram, so
+        # the host ends on lstsq
+        x, y = np.ones((12, 3), np.float32), np.arange(12.0)
+        make = lambda: LinearRegression(solver="normal")   # noqa: E731
+    else:
+        x, y = _cond_1e10()
+        make = lambda: LinearRegression(solver="normal",   # noqa: E731
+                                        fit_intercept=False)
+    xd = from_array(x, (64, x.shape[1]))
+    host, dev, rose = _fit_both(monkeypatch, make, xd, y)
+    assert rose["solve.host_fallback"] == 1 and rose["solve.device"] == 0
+    assert rose["solve.refine_steps"] == 0
+    np.testing.assert_array_equal(dev.coef_, host.coef_)
+    assert dev.intercept_ == host.intercept_
+    assert np.isfinite(dev.coef_).all()
+
+
+def test_small_systems_never_touch_the_device_solve(monkeypatch):
+    from repro.estimators import linear
+
+    def refuse(*args, **kwargs):
+        raise AssertionError("the device factor ran below its threshold")
+
+    monkeypatch.setattr(linear, "_normal_factor", refuse)
+    m = linear._DEVICE_SOLVE_MIN - 2               # order m + 1, one short
+    x, y, _ = regression_data(seed=4, n=3 * m, m=m)
+    before = repro.obs.snapshot("solve")
+    Ridge(alpha=1.0).fit(from_array(x, (128, 128)), y)
+    after = repro.obs.snapshot("solve")
+    assert all(after[k] == before.get(k, 0) for k in after)

@@ -357,6 +357,23 @@ def test_fit_driver_spans_nest_under_their_stages():
     assert names.count("fit.solve") == 1
 
 
+def test_device_solve_spans_factor_before_collect_and_refine_in_solve(
+        monkeypatch):
+    from repro.estimators import linear
+    monkeypatch.setattr(linear, "_DEVICE_SOLVE_MIN", 2)
+    obs.enable()
+    _fit_ridge()
+    obs.disable()
+    evts = obs.events()
+    top = [e["name"] for e in evts if e["parent"] is None]
+    assert top[:5] == ["fit.validate", "fit.stats", "fit.factor",
+                       "fit.collect", "fit.solve"]
+    refines = [e for e in evts if e["name"] == "fit.refine"]
+    # at least one correction, then the residual that meets the bound
+    assert len(refines) >= 2
+    assert {e["parent"] for e in refines} == {"fit.solve"}
+
+
 def test_serve_queue_records_carry_request_and_batch_ids():
     est = _fit_ridge()
     reg = serve.ModelRegistry()

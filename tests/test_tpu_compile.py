@@ -130,3 +130,21 @@ def test_summa_local_gemm_keeps_kernel_on_2x2_mesh(topo, on_tpu):
     a = _stacked((8, 8, 2048, 2048), jnp.float32, sharded)
     compiled = _compile(summa, a, a)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_normal_factor_compiles_for_v5e(one_chip):
+    """The Ridge solve's device half at the epsilon order (2,000 features
+    and the intercept, padded to 2,048) under ``highest``: the assembled
+    system's Cholesky factor, the first solution, and one correction."""
+    from repro.estimators import linear
+    m = 2000
+    f32 = jnp.float32
+    args = (_stacked((m, m), f32, one_chip), _stacked((m, 1), f32, one_chip),
+            _stacked((1, m), f32, one_chip), _stacked((3,), f32, one_chip))
+    with jax.default_matmul_precision("highest"):
+        factor = linear._normal_factor.lower(*args, intercept=True).compile()
+        chol, theta, _, _ = factor.out_info
+        assert chol.shape == (2048, 2048) and theta.shape == (m + 1,)
+        linear._factor_solve.lower(
+            _stacked((2048, 2048), f32, one_chip),
+            _stacked((2048,), f32, one_chip)).compile()
