@@ -13,6 +13,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+# the sizes a CPU test holds
+TINY = dict(n_rows=3000, n_features=300, block=[1024, 128], panel_rows=1024)
+
 
 def _panel(key, p, rows: int, m: int):
     return jax.random.normal(jax.random.fold_in(key, p), (rows, m),

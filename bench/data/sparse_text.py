@@ -19,6 +19,12 @@ from __future__ import annotations
 import numpy as np
 import scipy.sparse as sp
 
+# the sizes a CPU test holds: some thirty words a document; with rcv1's own
+# seventy-six, near-equal distances (whose order rounding may swap) are
+# rare; with three they are common and the Lloyd steps part ways
+TINY = dict(n_rows=3000, n_features=1000, density=0.03, block_rows=512,
+            block_nse=20000, vocab=20)
+
 
 def make(cell):
     """``(mat, y)``: a float32 CSR matrix with sorted column indices and no

@@ -9,7 +9,8 @@ its per-layer metrics), ``device``, with ``--trace 1`` ``breakdown``, and
 last ``checks``: each number compared with the plain reference beside its
 limit, which are also the last lines of standard error.  Where JAX finds
 no TPU, or fewer chips than the cell asks for, the run exits non-zero and
-prints no result.
+prints no result.  A cell on four chips is rehearsed on the CPU by the
+tests, in a child process with four host devices (``bench/tests/tiny.py``).
 """
 
 import time

@@ -1,7 +1,9 @@
 """Each cell, cut to a size a CPU test holds, runs through the harness's
 own entry (its look for a chip skipped) and gives the fields of a result
-line, with the profiler off and on; and ``bench/run.py`` itself refuses a
-machine without a TPU."""
+line, with the profiler off and on: a cell on one chip in the test's
+process, one on more in a child process with as many CPU devices
+(``tiny.outcome``).  And ``bench/run.py`` itself refuses a machine without
+a TPU."""
 
 import json
 import os
@@ -11,7 +13,7 @@ import sys
 
 import pytest
 
-from bench.lib.cell import ROOT
+from bench.lib.cell import ROOT, Cell
 from bench.tests import tiny
 
 CELLS = [w["name"] for w in json.loads(
@@ -41,9 +43,9 @@ def _assert_line(res, cell, trace):
 
 @pytest.mark.parametrize("trace", [False, True])
 @pytest.mark.parametrize("name", CELLS)
-def test_one_chip_cell_gives_a_result_line(name, trace, tmp_path):
-    c = tiny.cell(name)
-    _assert_line(tiny.run(c, trace, tmp=tmp_path), c, trace)
+def test_cell_gives_a_result_line(name, trace, tmp_path):
+    res = tiny.outcome(name, trace=trace, tmp=tmp_path)
+    _assert_line(res, Cell.load(name, tiny.SEED), trace)
 
 
 def _run_py(cwd, *extra):

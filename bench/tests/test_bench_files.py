@@ -1,7 +1,8 @@
 """The benchmark's files hold together: every cell names a configuration,
-an entry, a traffic driver and limits that exist; every name and unit is
-well formed; every per-layer metric has a reader and is reported where the
-metric it moves is."""
+an entry, a traffic driver and limits that exist, a data maker with the
+sizes a CPU test holds and an entry with planted faults; every name and
+unit is well formed; every per-layer metric has a reader and is reported
+where the metric it moves is."""
 
 import importlib
 import json
@@ -10,6 +11,7 @@ import re
 import pytest
 
 from bench.lib.cell import ROOT, Cell, metric_reader
+from bench.tests import faults
 
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -52,6 +54,14 @@ def test_cell_files_resolve(name):
     assert cell.params["limits"]
     assert "setup_s" in {m["name"] for m in cell.end_to_end}
     assert len(cell.end_to_end) >= 2 and cell.per_layer
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_cell_brings_tiny_sizes_and_faults(name):
+    cell = Cell.load(name, 1)
+    assert isinstance(getattr(cell.data(), "TINY", None), dict), \
+        cell.config["data"]
+    assert faults.of(cell.params["entry"]) is not None, cell.params["entry"]
 
 
 def test_config_files_state_their_source():

@@ -24,6 +24,8 @@ import numpy as np
 from bench.lib.runner import WindowResult
 
 GRACE_S = 60.0
+# the parameters a CPU test holds
+TINY_PARAMS = dict(pool_rows=600, rate_per_s=200)
 
 
 def schedule(cell, seconds: float, pool_rows: int):
